@@ -6,13 +6,9 @@
     on a CPU mesh — wall time + the HLO-counted ppermute traffic."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-
 from repro.core.fabric import Alternative, Fabric, Path, Use
 
-from benchmarks.common import row
+from benchmarks.common import row, run_host_cpu_child
 
 N = 200e9 / 8
 
@@ -61,14 +57,7 @@ with jax.set_mesh(mesh):
         nperm = hlo.count("collective-permute(")
         print(f"fig5b/ring_ag_bidir={bidir},{dt*1e6:.1f},permutes={nperm}")
 """
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=600, env=env,
-                         cwd=os.path.join(os.path.dirname(__file__), ".."))
-    print(out.stdout.strip())
-    if out.returncode != 0:
-        print(out.stderr[-1500:])
+    run_host_cpu_child(code)
 
 
 def main() -> None:

@@ -5,13 +5,9 @@ a fake 8-device mesh and count collective ops + bytes, then time them.
 The analytic part applies the path latency model: B ops pay B latencies."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-
 from repro.core import hw
 
-from benchmarks.common import row
+from benchmarks.common import row, run_host_cpu_child
 
 
 def model_part() -> None:
@@ -54,12 +50,7 @@ with jax.set_mesh(mesh):
         dt = (time.perf_counter() - t0)/20
         print(f"fig10/exec/{name},{dt*1e6:.1f},all_reduces={n_ar}")
 """
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=600,
-                         cwd=os.path.join(os.path.dirname(__file__), ".."))
-    print(out.stdout.strip())
-    if out.returncode != 0:
-        print(out.stderr[-1500:])
+    run_host_cpu_child(code)
 
 
 def main() -> None:

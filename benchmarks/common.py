@@ -4,6 +4,9 @@ collected in-process so drivers can emit machine-readable output
 (benchmarks/run.py --json)."""
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
 import time
 from typing import Callable, Dict, List
 
@@ -34,3 +37,21 @@ def row(name: str, us: float, derived: str = "") -> str:
     print(line)
     RESULTS.append({"name": name, "us": us, "derived": derived})
     return line
+
+
+def run_host_cpu_child(code: str, timeout: float = 600) -> None:
+    """Run ``python -c code`` pinned to the host CPU (it sets its own
+    virtual-device count) and record each ``name,us,derived`` line it
+    prints as a row labelled ``host-cpu``. A failed child fails the
+    calling section."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=timeout, env=env,
+                         cwd=os.path.join(os.path.dirname(__file__), ".."))
+    if out.returncode != 0:
+        raise RuntimeError(f"host-cpu child exited {out.returncode}:\n"
+                           f"{out.stderr[-1500:]}")
+    for line in out.stdout.strip().splitlines():
+        name, us, derived = line.split(",", 2)
+        row(name, float(us), f"host-cpu {derived}")

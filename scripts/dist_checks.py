@@ -144,21 +144,10 @@ def check_elastic_reshard():
     print("elastic reshard OK")
 
 
-def _supports_partial_manual() -> bool:
-    """Old XLA refuses PartitionId under partially-manual shard_map
-    (`auto=` axes), which the pod-sync step relies on."""
-    ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    return ver >= (0, 5)
-
-
 if __name__ == "__main__":
     check_collectives()
     check_moe_ep()
     check_cp_decode()
-    if _supports_partial_manual():
-        check_compressed_pod_sync()
-    else:
-        print(f"compressed pod sync SKIPPED (jax {jax.__version__} "
-              "lacks partial-manual SPMD support)")
+    check_compressed_pod_sync()
     check_elastic_reshard()
     print("ALL DISTRIBUTED CHECKS PASSED")

@@ -1,2 +1,1 @@
 """repro: off-path SmartNIC characterization, rebuilt for TPU meshes."""
-from repro import _jax_compat  # noqa: F401  (patches old jax in place)

@@ -2,7 +2,7 @@
 
 Each kernel package ships:
   kernel.py — pl.pallas_call with explicit BlockSpec VMEM tiling (TPU target)
-  ops.py    — jit'd public wrapper (interpret=True on CPU)
+  ops.py    — jit'd public wrapper (``interpret()`` picks the mode)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
 Kernels:
@@ -11,3 +11,12 @@ Kernels:
   ssd_scan         — Mamba2 SSD chunked scan (state carried across chunks)
   quant            — blockwise int8 compress/decompress (grad/ckpt/KV paths)
 """
+import jax
+
+
+def interpret() -> bool:
+    """Interpret-mode switch for every kernel wrapper: True only on the
+    CPU backend, which is where the tests run the kernel bodies. On a
+    TPU the kernels always compile to Mosaic; a chip run never
+    interprets."""
+    return jax.default_backend() == "cpu"

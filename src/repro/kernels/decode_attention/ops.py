@@ -6,11 +6,8 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import interpret
 from repro.kernels.decode_attention import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "kv_block"))
@@ -28,5 +25,5 @@ def decode_attention_kernel(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array
     vc = v_cache.swapaxes(1, 2)
     out = K.decode_attention_bhgd(qk, kc, vc, cache_len, window=window,
                                   softcap=softcap, kv_block=kv_block,
-                                  interpret=_on_cpu())
+                                  interpret=interpret())
     return out.reshape(b, hq, d)[:, None]

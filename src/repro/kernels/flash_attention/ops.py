@@ -6,11 +6,8 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import interpret
 from repro.kernels.flash_attention import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -25,5 +22,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = v.swapaxes(1, 2)
     out = K.flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                  softcap=softcap, q_block=q_block,
-                                 kv_block=kv_block, interpret=_on_cpu())
+                                 kv_block=kv_block, interpret=interpret())
     return out.swapaxes(1, 2)

@@ -1,6 +1,6 @@
-"""Public jit'd wrappers for the int8 quant kernels. On CPU (this
-container) they run the kernel body in interpret mode; on TPU the same
-call compiles to Mosaic."""
+"""Public jit'd wrappers for the int8 quant kernels. On the CPU backend
+they run the kernel body in interpret mode; on TPU the same call
+compiles to Mosaic."""
 from __future__ import annotations
 
 import functools
@@ -8,11 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret
 from repro.kernels.quant import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -23,13 +20,13 @@ def quantize_int8(x: jax.Array, block: int = 256):
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
     blocks = flat.reshape(-1, block)
-    q, s = K.quantize_int8_pallas(blocks, interpret=_on_cpu())
+    q, s = K.quantize_int8_pallas(blocks, interpret=interpret())
     return q, s
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype"))
 def dequantize_int8(q: jax.Array, scale: jax.Array, shape, dtype=jnp.float32):
-    out = K.dequantize_int8_pallas(q, scale, dtype=dtype, interpret=_on_cpu())
+    out = K.dequantize_int8_pallas(q, scale, dtype=dtype, interpret=interpret())
     n = 1
     for d in shape:
         n *= d

@@ -5,11 +5,8 @@ import functools
 
 import jax
 
+from repro.kernels import interpret
 from repro.kernels.ssd_scan import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "head_tile"))
@@ -18,4 +15,4 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
              head_tile: int = 8):
     """Mamba2 SSD: returns (y (B,S,H,P), final_state (B,H,P,N))."""
     return K.ssd_scan_pallas(x, dt, A, Bm, C, chunk=chunk,
-                             head_tile=head_tile, interpret=_on_cpu())
+                             head_tile=head_tile, interpret=interpret())

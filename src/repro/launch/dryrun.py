@@ -20,24 +20,11 @@ from repro.launch.inputs import (batch_shardings, batch_specs, decode_shardings,
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.models.params import abstract_params, num_groups
-from repro.optim.adamw import adamw_init
+from repro.optim.adamw import adamw_init, opt_logical
 from repro.parallel.sharding import named_sharding, tree_shardings
 from repro.train.train_step import make_train_step
-from repro.core.compression import Quantized
 
 RUNS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "runs", "dryrun")
-
-
-def _opt_logical(params_logical, int8: bool):
-    def leaf(lg):
-        if int8:
-            return Quantized(q=("flat_shard", None), scale=("flat_shard",))
-        return lg
-    is_lg = lambda x: isinstance(x, tuple) and all(
-        isinstance(e, (str, type(None))) for e in x)
-    m = jax.tree.map(leaf, params_logical, is_leaf=is_lg)
-    from repro.optim.adamw import AdamWState
-    return AdamWState(step=(), m=m, v=jax.tree.map(leaf, params_logical, is_leaf=is_lg))
 
 
 def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
@@ -76,7 +63,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             opt_abs = jax.eval_shape(
                 lambda: adamw_init(params_abs,
                                    moments="int8" if run.moments_int8 else "f32"))
-            opt_sh = tree_shardings(_opt_logical(logical, run.moments_int8),
+            opt_sh = tree_shardings(opt_logical(logical, run.moments_int8),
                                     opt_abs, mesh)
             bspecs = batch_specs(cfg, shape)
             bsh = batch_shardings(cfg, shape, mesh)

@@ -40,6 +40,8 @@ def main(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--kv-fabric", action="store_true",
                     help="plan decode cache placement on the §5.2 fabric")
     ap.add_argument("--staged", action="store_true",
@@ -68,7 +70,7 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params, _ = init_params(cfg, jax.random.PRNGKey(0))
+    params, _ = init_params(cfg, jax.random.PRNGKey(args.seed))
     from repro.serve.disagg import kv_fabric, kv_serve_time_model
     if args.staged:
         tracer = None
@@ -121,7 +123,7 @@ def main(argv=None):
               f"{trace.duration:.0f}s (mean {trace.mean_rate:.1f} req/s, "
               f"peak {trace.peak_rate:.1f} req/s, seed {args.trace_seed})")
     else:
-        rng = np.random.default_rng(0)
+        rng = np.random.default_rng(args.seed)
         reqs = []
         for i in range(args.requests):
             shape = ((args.prompt_len, cfg.num_codebooks)
@@ -157,4 +159,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

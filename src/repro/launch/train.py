@@ -38,23 +38,37 @@ from repro.ckpt.checkpoint import CheckpointManager
 from repro.launch.inputs import batch_shardings, param_shardings
 from repro.launch.mesh import make_production_mesh
 from repro.models.params import init_params, num_groups
-from repro.optim.adamw import adamw_init
+from repro.optim.adamw import adamw_init, opt_logical
 from repro.parallel.sharding import tree_shardings
 from repro.train.train_step import make_train_step
 from repro.train.trainer import Trainer
 
 
+def local_mesh(n_dev: int, multi_pod: bool = False):
+    """Best small (data, model) mesh (TP <= 2) over the first ``n_dev``
+    local devices."""
+    from repro.ft.elastic import best_mesh_for, make_mesh
+    shp, names = best_mesh_for(n_dev, model=min(2, n_dev),
+                               prefer_pods=2 if multi_pod else 1)
+    return make_mesh(shp, names)
+
+
 def build(cfg, run: RunConfig, shape: ShapeConfig, mesh, *, impl="auto"):
-    """Init sharded state + jitted step for (cfg, mesh)."""
-    from repro.launch.dryrun import _opt_logical  # reuse
+    """Init sharded state + jitted step for (cfg, mesh). Params and
+    optimizer state are created by jitted inits straight into their
+    shardings: no device ever holds an unsharded copy (at full width
+    the f32 AdamW moments alone exceed one chip's HBM)."""
+    moments = "int8" if run.moments_int8 else "f32"
     with jax.set_mesh(mesh):
         _, logical, psh = param_shardings(cfg, mesh)
-        params, _ = init_params(cfg, jax.random.PRNGKey(run.seed))
-        params = jax.device_put(params, psh)
-        opt = adamw_init(params, moments="int8" if run.moments_int8 else "f32")
-        opt_sh = tree_shardings(_opt_logical(logical, run.moments_int8),
-                                jax.eval_shape(lambda: opt), mesh)
-        opt = jax.device_put(opt, opt_sh)
+        params = jax.jit(lambda k: init_params(cfg, k)[0], out_shardings=psh)(
+            jax.random.PRNGKey(run.seed))
+        opt_abs = jax.eval_shape(lambda p: adamw_init(p, moments=moments),
+                                 params)
+        opt_sh = tree_shardings(opt_logical(logical, run.moments_int8),
+                                opt_abs, mesh)
+        opt = jax.jit(lambda p: adamw_init(p, moments=moments),
+                      out_shardings=opt_sh)(params)
         bsh = batch_shardings(cfg, shape, mesh)
         step = jax.jit(make_train_step(cfg, run, impl=impl, mesh=mesh),
                        in_shardings=(psh, opt_sh, bsh, None),
@@ -257,10 +271,7 @@ def main(argv=None):
     elif n_dev >= 256:
         mesh = make_production_mesh()
     else:  # local mode: best small mesh
-        from repro.ft.elastic import best_mesh_for, make_mesh
-        shp, names = best_mesh_for(n_dev, model=min(2, n_dev),
-                                   prefer_pods=2 if args.multi_pod else 1)
-        mesh = make_mesh(shp, names)
+        mesh = local_mesh(n_dev, args.multi_pod)
     print(f"[train] mesh={dict(mesh.shape)} devices={n_dev}")
 
     run = RunConfig(learning_rate=args.lr, total_steps=args.steps,
@@ -285,4 +296,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

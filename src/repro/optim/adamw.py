@@ -55,6 +55,19 @@ def adamw_init(params: PyTree, *, moments: str = "f32") -> AdamWState:
     return AdamWState(step=jnp.zeros((), jnp.int32), m=m, v=v)
 
 
+def opt_logical(params_logical: PyTree, int8: bool) -> AdamWState:
+    """Logical axes of the ``adamw_init`` state: m/v follow the params'
+    axes; int8 moments are flat blocks sharded over every mesh axis."""
+    def leaf(lg):
+        if int8:
+            return Quantized(q=("flat_shard", None), scale=("flat_shard",))
+        return lg
+    is_lg = lambda x: isinstance(x, tuple) and all(
+        isinstance(e, (str, type(None))) for e in x)
+    moments = jax.tree.map(leaf, params_logical, is_leaf=is_lg)
+    return AdamWState(step=(), m=moments, v=moments)
+
+
 def global_norm(tree: PyTree) -> jax.Array:
     return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
                         for x in jax.tree.leaves(tree)))

@@ -13,6 +13,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def test_distributed_checks():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "scripts", "dist_checks.py")],

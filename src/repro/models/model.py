@@ -3,6 +3,10 @@
 One forward covers train & prefill; ``decode_step`` covers single-token
 serving against a cache. Layers run under ``lax.scan`` over period-groups
 (HLO stays O(1) in depth) with optional remat.
+
+Named scopes (``jax.named_scope``, metadata only) mark each layer's parts
+in the compiled ops' names: ``embed``, ``attn`` or ``ssm`` (the mixer with
+its cache write), ``ffn`` (dense MLP or MoE) and ``lm_head``.
 """
 from __future__ import annotations
 
@@ -32,6 +36,7 @@ class ForwardResult(NamedTuple):
 # embeddings
 # ----------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
                  frontend_embeds: Optional[jax.Array] = None) -> jax.Array:
     table = params["embed"]["table"]
@@ -154,22 +159,25 @@ def apply_layer(cfg: ModelConfig, slot: int, p: dict, x: jax.Array, *,
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
     if kind["kind"] == "attn":
-        mix, new_cache = _attention_mixer(
-            cfg, kind, p["attn"], h, positions=positions, impl=impl,
-            cache=cache, pos=pos, cp_axis=cp_axis, mesh=mesh)
+        with jax.named_scope("attn"):
+            mix, new_cache = _attention_mixer(
+                cfg, kind, p["attn"], h, positions=positions, impl=impl,
+                cache=cache, pos=pos, cp_axis=cp_axis, mesh=mesh)
     else:
-        mix, new_cache = _ssm_mixer(cfg, p["ssm"], h, cache=cache, impl=impl)
+        with jax.named_scope("ssm"):
+            mix, new_cache = _ssm_mixer(cfg, p["ssm"], h, cache=cache, impl=impl)
     x = x + mix
     if kind["has_ffn"]:
         h = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
-        if kind["moe"]:
-            y, metrics = moe_ffn(h, p["moe"], num_experts=cfg.num_experts,
-                                 top_k=cfg.num_experts_per_tok,
-                                 activation=activation_fn(cfg.mlp_activation),
-                                 capacity_factor=capacity_factor)
-            aux = aux + metrics.aux_loss
-        else:
-            y = mlp(h, p["mlp"], activation_fn(cfg.mlp_activation))
+        with jax.named_scope("ffn"):
+            if kind["moe"]:
+                y, metrics = moe_ffn(h, p["moe"], num_experts=cfg.num_experts,
+                                     top_k=cfg.num_experts_per_tok,
+                                     activation=activation_fn(cfg.mlp_activation),
+                                     capacity_factor=capacity_factor)
+                aux = aux + metrics.aux_loss
+            else:
+                y = mlp(h, p["mlp"], activation_fn(cfg.mlp_activation))
         x = x + y
     x = constrain(x, "batch", "seq", "embed")
     return x, new_cache, aux
@@ -369,7 +377,8 @@ def decode_step(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
     x, new_cache = jax.lax.scan(group_body, x, (params["layers"], cache),
                                 unroll=unroll)
     x = rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = logits_for(cfg, params, x)
+    with jax.named_scope("lm_head"):
+        logits = logits_for(cfg, params, x)
     return logits, new_cache
 
 
@@ -399,62 +408,65 @@ def prefill(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
             kind = slot_kind(cfg, slot)
             h = rmsnorm(x, group_params[slot]["norm1"]["scale"], cfg.norm_eps)
             if kind["kind"] == "attn":
-                p = group_params[slot]["attn"]
-                xc = h.astype(jnp.bfloat16)
-                q = jnp.einsum("bsd,dhk->bshk", xc, p["wq"].astype(jnp.bfloat16))
-                k = jnp.einsum("bsd,dhk->bshk", xc, p["wk"].astype(jnp.bfloat16))
-                v = jnp.einsum("bsd,dhk->bshk", xc, p["wv"].astype(jnp.bfloat16))
-                q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
-                k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
-                window = cfg.window_size if kind["local"] else None
-                out = attn_mod.attention(q, k, v, causal=True, window=window,
-                                         softcap=cfg.attn_logit_softcap, impl=impl)
-                y = jnp.einsum("bshk,hkd->bsd", out.astype(jnp.bfloat16),
-                               p["wo"].astype(jnp.bfloat16))
-                x = x + y.astype(x.dtype)
-                kc = jnp.zeros((b, max_len, cfg.num_kv_heads, cfg.head_dim), cache_dtype)
-                kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(cache_dtype), 0, axis=1)
-                vc = jnp.zeros((b, max_len, cfg.num_kv_heads, cfg.head_dim), cache_dtype)
-                vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(cache_dtype), 0, axis=1)
-                new_slices.append({"k": kc, "v": vc})
+                with jax.named_scope("attn"):
+                    p = group_params[slot]["attn"]
+                    xc = h.astype(jnp.bfloat16)
+                    q = jnp.einsum("bsd,dhk->bshk", xc, p["wq"].astype(jnp.bfloat16))
+                    k = jnp.einsum("bsd,dhk->bshk", xc, p["wk"].astype(jnp.bfloat16))
+                    v = jnp.einsum("bsd,dhk->bshk", xc, p["wv"].astype(jnp.bfloat16))
+                    q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+                    k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
+                    window = cfg.window_size if kind["local"] else None
+                    out = attn_mod.attention(q, k, v, causal=True, window=window,
+                                             softcap=cfg.attn_logit_softcap, impl=impl)
+                    y = jnp.einsum("bshk,hkd->bsd", out.astype(jnp.bfloat16),
+                                   p["wo"].astype(jnp.bfloat16))
+                    x = x + y.astype(x.dtype)
+                    kc = jnp.zeros((b, max_len, cfg.num_kv_heads, cfg.head_dim), cache_dtype)
+                    kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(cache_dtype), 0, axis=1)
+                    vc = jnp.zeros((b, max_len, cfg.num_kv_heads, cfg.head_dim), cache_dtype)
+                    vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(cache_dtype), 0, axis=1)
+                    new_slices.append({"k": kc, "v": vc})
             else:
-                p = group_params[slot]["ssm"]
-                # full-sequence mix, but also keep final ssm/conv states
-                din, n, hh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-                xc = h.astype(jnp.bfloat16)
-                xz = jnp.einsum("bsd,dti->bsti", xc, p["w_xz"].astype(jnp.bfloat16))
-                x_in, z = xz[..., 0, :], xz[..., 1, :]
-                bc = jnp.einsum("bsd,dtn->bstn", xc, p["w_bc"].astype(jnp.bfloat16))
-                b_in, c_in = bc[..., 0, :], bc[..., 1, :]
-                dt_raw = jnp.einsum("bsd,dh->bsh", xc, p["w_dt"].astype(jnp.bfloat16))
-                A = -jnp.exp(p["A_log"].astype(jnp.float32))
-                x_conv, st_x = ssm_mod.causal_conv(x_in, p["conv_x"].astype(x_in.dtype))
-                b_conv, st_b = ssm_mod.causal_conv(b_in, p["conv_b"].astype(b_in.dtype))
-                c_conv, st_c = ssm_mod.causal_conv(c_in, p["conv_c"].astype(c_in.dtype))
-                x_conv, b_conv, c_conv = map(jax.nn.silu, (x_conv, b_conv, c_conv))
-                dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-                xhh = x_conv.reshape(b, s, hh, hd)
-                y, hfin = ssm_mod.ssd_chunked(xhh, dt, A, b_conv, c_conv, chunk=cfg.ssm_chunk)
-                y = y + xhh.astype(jnp.float32) * p["D"].astype(jnp.float32)[None, None, :, None]
-                y = y.reshape(b, s, din)
-                y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-                y = rmsnorm(y, p["norm"], cfg.norm_eps)
-                out = jnp.einsum("bsi,id->bsd", y.astype(jnp.bfloat16),
-                                 p["out"].astype(jnp.bfloat16))
-                x = x + out.astype(x.dtype)
-                new_slices.append({"h": hfin, "conv_x": st_x.astype(cache_dtype),
-                                   "conv_b": st_b.astype(cache_dtype),
-                                   "conv_c": st_c.astype(cache_dtype)})
+                with jax.named_scope("ssm"):
+                    p = group_params[slot]["ssm"]
+                    # full-sequence mix, but also keep final ssm/conv states
+                    din, n, hh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+                    xc = h.astype(jnp.bfloat16)
+                    xz = jnp.einsum("bsd,dti->bsti", xc, p["w_xz"].astype(jnp.bfloat16))
+                    x_in, z = xz[..., 0, :], xz[..., 1, :]
+                    bc = jnp.einsum("bsd,dtn->bstn", xc, p["w_bc"].astype(jnp.bfloat16))
+                    b_in, c_in = bc[..., 0, :], bc[..., 1, :]
+                    dt_raw = jnp.einsum("bsd,dh->bsh", xc, p["w_dt"].astype(jnp.bfloat16))
+                    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+                    x_conv, st_x = ssm_mod.causal_conv(x_in, p["conv_x"].astype(x_in.dtype))
+                    b_conv, st_b = ssm_mod.causal_conv(b_in, p["conv_b"].astype(b_in.dtype))
+                    c_conv, st_c = ssm_mod.causal_conv(c_in, p["conv_c"].astype(c_in.dtype))
+                    x_conv, b_conv, c_conv = map(jax.nn.silu, (x_conv, b_conv, c_conv))
+                    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+                    xhh = x_conv.reshape(b, s, hh, hd)
+                    y, hfin = ssm_mod.ssd_chunked(xhh, dt, A, b_conv, c_conv, chunk=cfg.ssm_chunk)
+                    y = y + xhh.astype(jnp.float32) * p["D"].astype(jnp.float32)[None, None, :, None]
+                    y = y.reshape(b, s, din)
+                    y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+                    y = rmsnorm(y, p["norm"], cfg.norm_eps)
+                    out = jnp.einsum("bsi,id->bsd", y.astype(jnp.bfloat16),
+                                     p["out"].astype(jnp.bfloat16))
+                    x = x + out.astype(x.dtype)
+                    new_slices.append({"h": hfin, "conv_x": st_x.astype(cache_dtype),
+                                       "conv_b": st_b.astype(cache_dtype),
+                                       "conv_c": st_c.astype(cache_dtype)})
             if kind["has_ffn"]:
                 h2 = rmsnorm(x, group_params[slot]["norm2"]["scale"], cfg.norm_eps)
-                if kind["moe"]:
-                    y2, _ = moe_ffn(h2, group_params[slot]["moe"],
-                                    num_experts=cfg.num_experts,
-                                    top_k=cfg.num_experts_per_tok,
-                                    activation=activation_fn(cfg.mlp_activation),
-                                    capacity_factor=None)
-                else:
-                    y2 = mlp(h2, group_params[slot]["mlp"], activation_fn(cfg.mlp_activation))
+                with jax.named_scope("ffn"):
+                    if kind["moe"]:
+                        y2, _ = moe_ffn(h2, group_params[slot]["moe"],
+                                        num_experts=cfg.num_experts,
+                                        top_k=cfg.num_experts_per_tok,
+                                        activation=activation_fn(cfg.mlp_activation),
+                                        capacity_factor=None)
+                    else:
+                        y2 = mlp(h2, group_params[slot]["mlp"], activation_fn(cfg.mlp_activation))
                 x = x + y2
         return x, tuple(new_slices)
 
@@ -466,5 +478,6 @@ def prefill(cfg: ModelConfig, params: PyTree, tokens: jax.Array,
     else:
         npos = jnp.asarray(length, jnp.int32)
         x_last = jax.lax.dynamic_slice_in_dim(x, npos - 1, 1, axis=1)
-    logits = logits_for(cfg, params, x_last)
+    with jax.named_scope("lm_head"):
+        logits = logits_for(cfg, params, x_last)
     return logits, cache, npos

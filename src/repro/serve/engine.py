@@ -30,15 +30,39 @@ changes *when* a token exists on the simulated clock, never *which*
 token it is. The simulated-time model is ``ServeTimeModel``: real jax
 compute runs eagerly, and its communication cost (prefill KV-cache
 shipment, per-step decode cache reads) is charged as fabric transfers.
+
+Observability on the host clock: the jax path writes ``serve.*`` spans
+into the JAX profiler's trace (``jax.profiler.TraceAnnotation``; about a
+microsecond each when no profiler runs), so they share a clock with the
+device's own events, and the two jitted programs are named functions
+(modules ``jit_decode_step`` and ``jit_prefill``). Spans of one thread
+nest; those of one request carry its ``rid``:
+
+``serve.step``          ``ServeEngine.step()``; ``active`` slots decoded
+``serve.admit``         one admission, queue pop to ``_activate``
+                        (``rid``, ``prompt_len``, ``bucket``)
+``serve.prefill_call``  the prefill program's call (``rid``, ``bucket``)
+``serve.splice``        the prefilled cache into its slot (``slot``)
+``serve.decode_call``   the decode program's call (``active``)
+``serve.sample``        ``_finish_decode``: tokens out, requests retired
+``serve.fetch``         one device-to-host read (``what``)
+
+``stats`` counts, besides tokens and steps: ``host_fetches`` (reads
+through ``_fetch``), ``decode_slot_steps`` (active slots summed over
+decode steps) and ``admitted`` (requests taken off the queue). Each
+``Request`` carries host-clock stamps ``submitted_at`` and
+``admitted_at`` (``time.perf_counter()``).
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.configs.base import ModelConfig
 from repro.core.fabric import Fabric
@@ -59,6 +83,8 @@ class Request:
     first_token_time: Optional[float] = None   # simulated TTFT timestamp
     finish_time: Optional[float] = None
     placement: Optional[str] = None     # decode-cache placement decision
+    submitted_at: Optional[float] = None    # host clock (perf_counter)
+    admitted_at: Optional[float] = None     # host clock: off the queue
 
     @property
     def ttft(self) -> Optional[float]:
@@ -122,7 +148,8 @@ class _EngineCore:
         self.finished: List[Request] = []   # retired, not yet drained by run()
         self.stats: Dict[str, float] = {
             "prefill_tokens": 0, "decode_steps": 0,
-            "prefill_compilations": 0, "prefill_padded_tokens": 0}
+            "prefill_compilations": 0, "prefill_padded_tokens": 0,
+            "host_fetches": 0, "decode_slot_steps": 0, "admitted": 0}
         self._compiled_buckets: set = set()
         if compute == "sim":
             self.cache = None
@@ -139,11 +166,18 @@ class _EngineCore:
         self._attn_only = all(slot_kind(cfg, s)["kind"] == "attn"
                               for s in range(layer_period(cfg)))
         self.bucket_prefill = bucket_prefill and self._attn_only
-        self._decode = jax.jit(
-            lambda p, t, c, pos: M.decode_step(cfg, p, t, c, pos, impl=impl))
-        self._prefill = jax.jit(
-            lambda p, t, n: M.prefill(cfg, p, t, max_len, impl=impl,
-                                      cache_dtype=cache_dtype, length=n))
+
+        # named functions, so that their modules are jit_decode_step and
+        # jit_prefill in compiled code and in the profiler's trace
+        def decode_step(p, t, c, pos):
+            return M.decode_step(cfg, p, t, c, pos, impl=impl)
+
+        def prefill(p, t, n):
+            return M.prefill(cfg, p, t, max_len, impl=impl,
+                             cache_dtype=cache_dtype, length=n)
+
+        self._decode = jax.jit(decode_step)
+        self._prefill = jax.jit(prefill)
 
     @staticmethod
     def _sim_token(rid: int, i: int) -> int:
@@ -152,7 +186,21 @@ class _EngineCore:
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
+
+    def _take(self, idx: int) -> Request:
+        """Pop queued request ``idx`` for admission."""
+        req = self.queue.pop(idx)
+        req.admitted_at = time.perf_counter()
+        self.stats["admitted"] += 1
+        return req
+
+    def _fetch(self, x, what: str) -> np.ndarray:
+        """Every device-to-host read of the jax path goes through here."""
+        self.stats["host_fetches"] += 1
+        with span("serve.fetch", what=what):
+            return np.asarray(x)
 
     def _bucket_len(self, n: int) -> int:
         """Pad target: next power of two (>= MIN_BUCKET), clamped to the
@@ -178,14 +226,15 @@ class _EngineCore:
             prompt = np.concatenate([prompt, pad])
         self._compiled_buckets.add((bucket,) + prompt.shape[1:])
         toks = jnp.asarray(prompt)[None]                  # (1, S[,C])
-        logits, cache1, npos = self._prefill(self.params, toks,
-                                             jnp.asarray(n, jnp.int32))
+        with span("serve.prefill_call", rid=req.rid, bucket=bucket):
+            logits, cache1, npos = self._prefill(self.params, toks,
+                                                 jnp.asarray(n, jnp.int32))
         tok = self._sample(logits[:, -1], req.temperature)
-        req.out_tokens.append(int(np.asarray(tok).reshape(-1)[0]))
+        req.out_tokens.append(int(self._fetch(tok, "token").reshape(-1)[0]))
         self.stats["prefill_tokens"] += n
         self.stats["prefill_padded_tokens"] += bucket - n
         self.stats["prefill_compilations"] = len(self._compiled_buckets)
-        return cache1, int(npos)
+        return cache1, int(self._fetch(npos, "npos"))
 
     def _splice_cache(self, slot: int, row_cache):
         """Copy a prefilled (batch=1) cache into slot `slot`."""
@@ -198,8 +247,9 @@ class _EngineCore:
             self.pos[slot] = npos
             self.active[slot] = req
             return
-        self._splice_cache(slot, cache1)
-        self.pos = self.pos.at[slot].set(npos)
+        with span("serve.splice", slot=slot):
+            self._splice_cache(slot, cache1)
+            self.pos = self.pos.at[slot].set(npos)
         self.active[slot] = req
 
     def _sample(self, logits: jax.Array, temperature: float) -> jax.Array:
@@ -222,12 +272,14 @@ class _EngineCore:
         for s in act:
             last[s] = self.active[s].out_tokens[-1]
         tokens = jnp.asarray(last)[:, None]                    # (B,1[,C])
-        logits, self.cache = self._decode(self.params, tokens, self.cache,
-                                          self.pos)
+        with span("serve.decode_call", active=len(act)):
+            logits, self.cache = self._decode(self.params, tokens, self.cache,
+                                              self.pos)
         self.pos = self.pos + jnp.asarray(
             [1 if self.active[s] is not None else 0 for s in range(self.slots)],
             jnp.int32)
         self.stats["decode_steps"] += 1
+        self.stats["decode_slot_steps"] += len(act)
         return logits
 
     def _finish_decode(self, act: List[int], logits) -> List[Request]:
@@ -245,23 +297,25 @@ class _EngineCore:
                     self.finished.append(req)
                     retired.append(req)
             return retired
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        retired: List[Request] = []
-        for s in act:
-            req = self.active[s]
-            if req.temperature > 0:
-                tok = self._sample(logits[s:s + 1, 0], req.temperature)
-                val = np.asarray(tok).reshape(-1)
-            else:
-                val = nxt[s].reshape(-1)
-            req.out_tokens.append(int(val[0]) if val.size == 1 else val.tolist())
-            if len(req.out_tokens) >= req.max_new_tokens or \
-                    int(self.pos[s]) >= self.max_len - 1:
-                req.done = True
-                self.active[s] = None
-                self.finished.append(req)
-                retired.append(req)
-        return retired
+        with span("serve.sample"):
+            nxt = self._fetch(jnp.argmax(logits[:, 0], axis=-1), "argmax")
+            retired: List[Request] = []
+            for s in act:
+                req = self.active[s]
+                if req.temperature > 0:
+                    tok = self._sample(logits[s:s + 1, 0], req.temperature)
+                    val = self._fetch(tok, "token").reshape(-1)
+                else:
+                    val = nxt[s].reshape(-1)
+                req.out_tokens.append(int(val[0]) if val.size == 1
+                                      else val.tolist())
+                if len(req.out_tokens) >= req.max_new_tokens or \
+                        int(self._fetch(self.pos[s], "pos")) >= self.max_len - 1:
+                    req.done = True
+                    self.active[s] = None
+                    self.finished.append(req)
+                    retired.append(req)
+            return retired
 
     def _free_slot(self) -> Optional[int]:
         for s in range(self.slots):
@@ -331,38 +385,45 @@ class ServeEngine(_EngineCore):
                         if self._arrived(r)), None)
             if idx is None:
                 break
-            req = self.queue.pop(idx)
-            if self.placement is not None:
-                req.placement = self.placement.location
-            cache1, npos = self._prefill_request(req)
-            if self.tm is not None:
-                amt = len(np.asarray(req.prompt)) * self.tm.prefill_units_per_token
-                self._charge(self.tm.prefill_path, amt, f"prefill:{req.rid}")
-            req.first_token_time = self._now()
-            if req.first_token_time is not None:
-                self.ttft_log.append((req.first_token_time, req.ttft))
-            self._activate(s, req, cache1, npos)
+            req = self._take(idx)
+            n = len(np.asarray(req.prompt))
+            with span("serve.admit", rid=req.rid, prompt_len=n,
+                      bucket=self._bucket_len(n)):
+                if self.placement is not None:
+                    req.placement = self.placement.location
+                cache1, npos = self._prefill_request(req)
+                if self.tm is not None:
+                    self._charge(self.tm.prefill_path,
+                                 n * self.tm.prefill_units_per_token,
+                                 f"prefill:{req.rid}")
+                req.first_token_time = self._now()
+                if req.first_token_time is not None:
+                    self.ttft_log.append((req.first_token_time, req.ttft))
+                self._activate(s, req, cache1, npos)
 
     # ------------------------------------------------------------------
     def step(self) -> int:
         """Admit + one decode step for all active slots. Returns number
         of active requests."""
-        self._advance_to_next_arrival()
-        self._admit()
-        act = [s for s in range(self.slots) if self.active[s] is not None]
-        if not act:
-            return 0
-        logits = self._decode_compute(act)
-        if self.tm is not None:
-            placements = {self.active[s].placement for s in act}
-            for pl in sorted(placements, key=str):
-                n = sum(1 for s in act if self.active[s].placement == pl)
-                self._charge(self.tm.decode_path_for(pl),
-                             n * self.tm.decode_units_per_slot, f"decode:{pl}")
-        retired = self._finish_decode(act, logits)
-        for req in retired:
-            req.finish_time = self._now()
-        return len(act)
+        with span("serve.step") as sp:
+            self._advance_to_next_arrival()
+            self._admit()
+            act = [s for s in range(self.slots) if self.active[s] is not None]
+            sp.set_metadata(active=len(act))
+            if not act:
+                return 0
+            logits = self._decode_compute(act)
+            if self.tm is not None:
+                placements = {self.active[s].placement for s in act}
+                for pl in sorted(placements, key=str):
+                    n = sum(1 for s in act if self.active[s].placement == pl)
+                    self._charge(self.tm.decode_path_for(pl),
+                                 n * self.tm.decode_units_per_slot,
+                                 f"decode:{pl}")
+            retired = self._finish_decode(act, logits)
+            for req in retired:
+                req.finish_time = self._now()
+            return len(act)
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drive step() until queues drain; returns (and drains) the
@@ -398,7 +459,7 @@ class PrefillStage:
         while True:
             while eng.queue and not eng.intake_paused \
                     and self.inflight < self.max_inflight:
-                req = eng.queue.pop(0)
+                req = eng._take(0)
                 self.inflight += 1
                 eng.runtime.process(self._one(req), name=f"prefill:{req.rid}")
             yield eng.arrived
@@ -710,6 +771,7 @@ class StagedServeEngine(_EngineCore):
     # ------------------------------------------------------------------
     def submit(self, req: Request):
         """Requests enter the queue at their ``arrival`` time."""
+        req.submitted_at = time.perf_counter()
         self._n_open += 1
         self.clock.at(max(req.arrival, self.clock.now), self._on_arrival, req)
 

@@ -34,9 +34,9 @@ shipment, per-step decode cache reads) is charged as fabric transfers.
 Observability on the host clock: the jax path writes ``serve.*`` spans
 into the JAX profiler's trace (``jax.profiler.TraceAnnotation``; about a
 microsecond each when no profiler runs), so they share a clock with the
-device's own events, and the two jitted programs are named functions
-(modules ``jit_decode_step`` and ``jit_prefill``). Spans of one thread
-nest; those of one request carry its ``rid``:
+device's own events, and the jitted programs are named functions
+(modules ``jit_decode_step``, ``jit_prefill`` and ``jit_greedy_pick``).
+Spans of one thread nest; those of one request carry its ``rid``:
 
 ``serve.step``          ``ServeEngine.step()``; ``active`` slots decoded
 ``serve.admit``         one admission, queue pop to ``_activate``
@@ -45,11 +45,17 @@ nest; those of one request carry its ``rid``:
 ``serve.splice``        the prefilled cache into its slot (``slot``)
 ``serve.decode_call``   the decode program's call (``active``)
 ``serve.sample``        ``_finish_decode``: tokens out, requests retired
-``serve.fetch``         one device-to-host read (``what``)
+``serve.fetch``         one device-to-host read (``what``: ``argmax``,
+                        a decode step's greedy picks; ``token``, a
+                        prefill's first token or a token sampled at
+                        ``temperature > 0``)
 
 ``stats`` counts, besides tokens and steps: ``host_fetches`` (reads
 through ``_fetch``), ``decode_slot_steps`` (active slots summed over
-decode steps) and ``admitted`` (requests taken off the queue). Each
+decode steps) and ``admitted`` (requests taken off the queue). Slot
+positions are host integers (``pos``), so a greedy decode step makes one
+read and runs one small program (``jit_greedy_pick``) besides the decode
+step; an admission makes one read, its first token. Each
 ``Request`` carries host-clock stamps ``submitted_at`` and
 ``admitted_at`` (``time.perf_counter()``).
 """
@@ -151,15 +157,14 @@ class _EngineCore:
             "prefill_compilations": 0, "prefill_padded_tokens": 0,
             "host_fetches": 0, "decode_slot_steps": 0, "admitted": 0}
         self._compiled_buckets: set = set()
+        self.pos = np.zeros((slots,), np.int32)         # next write index
         if compute == "sim":
             self.cache = None
-            self.pos = np.zeros((slots,), np.int64)
             self.bucket_prefill = False
             return
         if cfg is None:
             raise ValueError("compute='jax' needs a ModelConfig")
         self.cache, _ = M.init_cache(cfg, slots, max_len, cache_dtype)
-        self.pos = jnp.zeros((slots,), jnp.int32)       # next write index
         self.key = jax.random.PRNGKey(seed)
         # bucketing needs causal attention's inert pad tail; SSM state
         # runs through every position, so those configs prefill exact.
@@ -176,8 +181,12 @@ class _EngineCore:
             return M.prefill(cfg, p, t, max_len, impl=impl,
                              cache_dtype=cache_dtype, length=n)
 
+        def greedy_pick(logits):                        # (B, 1[, C], V)
+            return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+
         self._decode = jax.jit(decode_step)
         self._prefill = jax.jit(prefill)
+        self._greedy = jax.jit(greedy_pick)
 
     @staticmethod
     def _sim_token(rid: int, i: int) -> int:
@@ -212,29 +221,30 @@ class _EngineCore:
 
     def _prefill_request(self, req: Request) -> Tuple[Any, int]:
         """Real prefill compute for one request (bucketed): appends the
-        first output token and returns (cache_row, next_pos)."""
+        first output token and returns (cache_row, next_pos); the next
+        position is the prompt length."""
+        prompt = np.asarray(req.prompt)
+        n = prompt.shape[0]
         if self.compute == "sim":
-            n = len(np.asarray(req.prompt))
             req.out_tokens.append(self._sim_token(req.rid, 0))
             self.stats["prefill_tokens"] += n
             return None, n
-        prompt = np.asarray(req.prompt)
-        n = prompt.shape[0]
         bucket = self._bucket_len(n)
         if bucket > n:
             pad = np.zeros((bucket - n,) + prompt.shape[1:], prompt.dtype)
             prompt = np.concatenate([prompt, pad])
         self._compiled_buckets.add((bucket,) + prompt.shape[1:])
-        toks = jnp.asarray(prompt)[None]                  # (1, S[,C])
+        toks = jnp.asarray(prompt[None])                  # (1, S[,C])
         with span("serve.prefill_call", rid=req.rid, bucket=bucket):
-            logits, cache1, npos = self._prefill(self.params, toks,
-                                                 jnp.asarray(n, jnp.int32))
-        tok = self._sample(logits[:, -1], req.temperature)
+            logits, cache1, _ = self._prefill(self.params, toks,
+                                              jnp.asarray(n, jnp.int32))
+        tok = (self._greedy(logits) if req.temperature <= 0
+               else self._sample(logits[:, -1], req.temperature))
         req.out_tokens.append(int(self._fetch(tok, "token").reshape(-1)[0]))
         self.stats["prefill_tokens"] += n
         self.stats["prefill_padded_tokens"] += bucket - n
         self.stats["prefill_compilations"] = len(self._compiled_buckets)
-        return cache1, int(self._fetch(npos, "npos"))
+        return cache1, n
 
     def _splice_cache(self, slot: int, row_cache):
         """Copy a prefilled (batch=1) cache into slot `slot`."""
@@ -243,74 +253,61 @@ class _EngineCore:
         self.cache = jax.tree.map(put, self.cache, row_cache)
 
     def _activate(self, slot: int, req: Request, cache1, npos: int):
-        if self.compute == "sim":
-            self.pos[slot] = npos
-            self.active[slot] = req
-            return
-        with span("serve.splice", slot=slot):
-            self._splice_cache(slot, cache1)
-            self.pos = self.pos.at[slot].set(npos)
+        if self.compute == "jax":
+            with span("serve.splice", slot=slot):
+                self._splice_cache(slot, cache1)
+        self.pos[slot] = npos
         self.active[slot] = req
 
     def _sample(self, logits: jax.Array, temperature: float) -> jax.Array:
-        if temperature <= 0:
-            return jnp.argmax(logits, axis=-1)
+        """A token drawn at ``temperature > 0`` (greedy: ``_greedy``)."""
         self.key, sub = jax.random.split(self.key)
         return jax.random.categorical(sub, logits / temperature, axis=-1)
 
     # ------------------------------------------------------------------
     def _decode_compute(self, act: List[int]) -> Optional[jax.Array]:
-        """One real decode step for the active slots; returns logits."""
-        if self.compute == "sim":
-            for s in range(self.slots):
-                if self.active[s] is not None:
-                    self.pos[s] += 1
-            self.stats["decode_steps"] += 1
-            return None
-        cb = self.cfg.num_codebooks
-        last = np.zeros((self.slots,) + ((cb,) if cb > 1 else ()), np.int32)
-        for s in act:
-            last[s] = self.active[s].out_tokens[-1]
-        tokens = jnp.asarray(last)[:, None]                    # (B,1[,C])
-        with span("serve.decode_call", active=len(act)):
-            logits, self.cache = self._decode(self.params, tokens, self.cache,
-                                              self.pos)
-        self.pos = self.pos + jnp.asarray(
-            [1 if self.active[s] is not None else 0 for s in range(self.slots)],
-            jnp.int32)
+        """One decode step for the active slots; returns logits (None in
+        sim mode)."""
+        logits = None
+        if self.compute == "jax":
+            cb = self.cfg.num_codebooks
+            last = np.zeros((self.slots, 1) + ((cb,) if cb > 1 else ()),
+                            np.int32)                          # (B,1[,C])
+            for s in act:
+                last[s, 0] = self.active[s].out_tokens[-1]
+            # a copy of the positions: the host advances its own in place
+            # while the step may still be reading its argument
+            with span("serve.decode_call", active=len(act)):
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(last), self.cache,
+                    jnp.asarray(self.pos.copy()))
+            self.stats["decode_slot_steps"] += len(act)
+        self.pos[act] += 1
         self.stats["decode_steps"] += 1
-        self.stats["decode_slot_steps"] += len(act)
         return logits
 
     def _finish_decode(self, act: List[int], logits) -> List[Request]:
         """Append sampled tokens, retire finished requests."""
-        if self.compute == "sim":
-            retired = []
-            for s in act:
-                req = self.active[s]
-                req.out_tokens.append(
-                    self._sim_token(req.rid, len(req.out_tokens)))
-                if len(req.out_tokens) >= req.max_new_tokens or \
-                        int(self.pos[s]) >= self.max_len - 1:
-                    req.done = True
-                    self.active[s] = None
-                    self.finished.append(req)
-                    retired.append(req)
-            return retired
         with span("serve.sample"):
-            nxt = self._fetch(jnp.argmax(logits[:, 0], axis=-1), "argmax")
+            nxt = (None if self.compute == "sim"
+                   else self._fetch(self._greedy(logits), "argmax"))
             retired: List[Request] = []
             for s in act:
                 req = self.active[s]
-                if req.temperature > 0:
-                    tok = self._sample(logits[s:s + 1, 0], req.temperature)
-                    val = self._fetch(tok, "token").reshape(-1)
+                if nxt is None:
+                    req.out_tokens.append(
+                        self._sim_token(req.rid, len(req.out_tokens)))
                 else:
-                    val = nxt[s].reshape(-1)
-                req.out_tokens.append(int(val[0]) if val.size == 1
-                                      else val.tolist())
+                    if req.temperature > 0:
+                        tok = self._sample(logits[s:s + 1, 0],
+                                           req.temperature)
+                        val = self._fetch(tok, "token").reshape(-1)
+                    else:
+                        val = nxt[s].reshape(-1)
+                    req.out_tokens.append(int(val[0]) if val.size == 1
+                                          else val.tolist())
                 if len(req.out_tokens) >= req.max_new_tokens or \
-                        int(self._fetch(self.pos[s], "pos")) >= self.max_len - 1:
+                        self.pos[s] >= self.max_len - 1:
                     req.done = True
                     self.active[s] = None
                     self.finished.append(req)

@@ -96,6 +96,45 @@ def test_engine_fabric_placement(small_lm):
     assert eng2.placement is None
 
 
+def test_engine_retires_a_request_the_cache_cuts(small_lm):
+    """A request longer than the cache retires once its host-side position
+    reaches ``max_len - 1``: ``max_len - prompt`` tokens, the first
+    ``max_len - prompt`` of its uncut answer. A request in the other slot
+    serves on, as it does alone."""
+    cfg, params = small_lm
+    max_len = 32
+    rng = np.random.default_rng(4)
+    long_prompt = rng.integers(0, cfg.vocab_size, 20).astype(np.int32)
+    short_prompt = rng.integers(0, cfg.vocab_size, 5).astype(np.int32)
+
+    def serve(max_len, *reqs):
+        eng = ServeEngine(cfg, params, slots=2, max_len=max_len, impl="ref")
+        for r in reqs:
+            eng.submit(r)
+        positions = []
+        while eng.queue or any(eng.active):
+            eng.step()
+            positions.append(eng.pos.copy())
+        return eng, positions
+
+    cut = Request(rid=0, prompt=long_prompt, max_new_tokens=100)
+    other = Request(rid=1, prompt=short_prompt, max_new_tokens=20)
+    eng, positions = serve(max_len, cut, other)
+    assert cut.done and len(cut.out_tokens) == max_len - len(long_prompt)
+    # one position a decode step; the step that retired it is the first
+    # to leave slot 0 at the cache's last position
+    steps = len(cut.out_tokens) - 1
+    assert [int(p[0]) for p in positions[:steps]] == \
+        list(range(len(long_prompt) + 1, max_len))
+    assert eng.active[0] is None and other.done
+    alone = Request(rid=1, prompt=short_prompt, max_new_tokens=20)
+    serve(max_len, alone)
+    assert other.out_tokens == alone.out_tokens and len(alone.out_tokens) == 20
+    uncut = Request(rid=0, prompt=long_prompt, max_new_tokens=100)
+    serve(128, uncut)
+    assert cut.out_tokens == uncut.out_tokens[:len(cut.out_tokens)]
+
+
 def test_disagg_data_plane_correct():
     kv = DisaggKV(KVStoreParams(n_keys=5000, soc_cache_keys=500))
     rng = np.random.default_rng(0)

@@ -18,8 +18,7 @@ SHAPES = [(5, 4), (12, 6), (9, 3)]
 PARENT = {"serve.step": None, "serve.admit": "serve.step",
           "serve.prefill_call": "serve.admit", "serve.splice": "serve.admit",
           "serve.decode_call": "serve.step", "serve.sample": "serve.step"}
-FETCH_PARENT = {"token": "serve.admit", "npos": "serve.admit",
-                "argmax": "serve.sample", "pos": "serve.sample"}
+FETCH_PARENT = {"token": "serve.admit", "argmax": "serve.sample"}
 
 
 def _serve(cfg, params):
@@ -120,11 +119,35 @@ def test_the_counters(served):
     assert st["decode_steps"] == steps
     assert st["decode_slot_steps"] == sum(active)
     assert st["admitted"] == len(reqs)
-    # one argmax read per decode step and one position read per active
-    # slot, but none for a request retiring on its last token (the test
-    # on max_new_tokens comes first); two reads per prefill
-    assert st["host_fetches"] == (st["decode_steps"] + st["decode_slot_steps"]
-                                  + 2 * st["admitted"] - len(reqs))
+    # greedy: one read per decode step (every slot's pick at once) and
+    # one per admission (its first token); positions are host integers
+    assert st["host_fetches"] == st["decode_steps"] + st["admitted"]
+
+
+def _programs(events):
+    """Names of the executed programs in launch order: each host launch
+    joined on its ``run_id`` to the module of the operations it ran."""
+    module = {e[4]["run_id"]: e[4]["hlo_module"] for e in events
+              if "hlo_module" in e[4] and "run_id" in e[4]}
+    launches = sorted((e[2], e[4]["run_id"]) for e in events
+                      if e[1] == "PjRtCpuExecutable::ExecuteHelper")
+    return [module[r] for _, r in launches if r in module]
+
+
+def test_one_greedy_pick_between_decode_steps(served):
+    """Between two decode executions with no admission (prefill) between
+    them, the only program is the greedy pick: positions and tokens go up
+    as host arrays, so no eager device operation runs in the gap."""
+    _, reqs, active, _, events = served
+    progs = _programs(events)
+    at = [i for i, p in enumerate(progs) if p == "jit_decode_step"]
+    assert len(at) == sum(1 for a in active if a)
+    gaps = [progs[i + 1:j] for i, j in zip(at, at[1:])]
+    plain = [g for g in gaps if "jit_prefill" not in g]
+    assert plain and all(g == ["jit_greedy_pick"] for g in plain), gaps
+    assert progs[at[-1] + 1:] == ["jit_greedy_pick"]
+    # one pick per decode step and one per greedy prefill
+    assert progs.count("jit_greedy_pick") == len(at) + len(reqs)
 
 
 def test_the_host_clock_stamps(served):

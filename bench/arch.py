@@ -1,85 +1,68 @@
-"""A configuration file of ``configs/`` read into the sizes the benchmark
-uses, and into the program's ``ModelConfig``.
+"""A configuration file of ``configs/`` read into what every cell shares,
+with the sizes that its model module reads.
 
 The file holds the published ``config.json`` keys as they are run (each
-key changed from the source is listed under ``reduced``) and the
-engine's settings under ``engine``.
+key changed from the source is listed under ``reduced``), the engine's
+settings under ``engine``, and under ``model`` the name of the module of
+``models/`` that knows the architecture: its sizes, the program's
+``ModelConfig``, the weight layout, the plain reference and the counts
+of operations and bytes. Adding an architecture adds such a module.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
+import re
 from pathlib import Path
+from types import ModuleType
+from typing import Any
+
+
+def load_model(name: str) -> ModuleType:
+    """The model module ``models/<name>.py``."""
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise ValueError(f'"model": {name!r} is not the name of a module')
+    try:
+        return importlib.import_module(f"models.{name}")
+    except ModuleNotFoundError as e:
+        if e.name not in ("models", f"models.{name}"):
+            raise
+        raise ValueError(f'"model": {name!r} names no module of models/') from e
 
 
 @dataclasses.dataclass(frozen=True)
 class Arch:
     name: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
     vocab: int
-    experts: int
-    top_k: int
-    tied: bool
-    rope_theta: float
-    norm_eps: float
     slots: int
     max_len: int
     cache_dtype: str
     weights_dtype: str
+    model: str
+    sizes: Any                  # the model module's record of the sizes
+    module: ModuleType = dataclasses.field(compare=False, repr=False)
 
     @classmethod
     def from_file(cls, path: Path) -> "Arch":
-        d = json.loads(Path(path).read_text())
-        if d.get("hidden_act", "silu") != "silu":
-            raise ValueError(f"{path}: only SwiGLU (silu) models are supported")
+        try:
+            return cls.from_dict(json.loads(Path(path).read_text()))
+        except ValueError as e:
+            raise ValueError(f"{path}: {e}") from e
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "Arch":
+        if "model" not in d:
+            raise ValueError('no "model" key: it names the module of models/ '
+                             'that runs this architecture')
+        module = load_model(d["model"])
         eng = d["engine"]
-        heads = int(d["num_attention_heads"])
-        return cls(
-            name=d["name"],
-            layers=int(d["num_hidden_layers"]),
-            d_model=int(d["hidden_size"]),
-            heads=heads,
-            kv_heads=int(d.get("num_key_value_heads", heads)),
-            head_dim=int(d.get("head_dim", int(d["hidden_size"]) // heads)),
-            d_ff=int(d["intermediate_size"]),
-            vocab=int(d["vocab_size"]),
-            experts=int(d.get("num_local_experts", 0)),
-            top_k=int(d.get("num_experts_per_tok", 0)),
-            tied=bool(d.get("tie_word_embeddings", False)),
-            rope_theta=float(d["rope_theta"]),
-            norm_eps=float(d["rms_norm_eps"]),
-            slots=int(eng["slots"]),
-            max_len=int(eng["max_len"]),
-            cache_dtype=eng["cache_dtype"],
-            weights_dtype=eng["weights_dtype"])
+        return cls(name=d["name"], vocab=int(d["vocab_size"]),
+                   slots=int(eng["slots"]), max_len=int(eng["max_len"]),
+                   cache_dtype=eng["cache_dtype"],
+                   weights_dtype=eng["weights_dtype"], model=d["model"],
+                   sizes=module.sizes(d), module=module)
 
     def model_config(self):
         """The program's ``ModelConfig`` for these sizes."""
-        from repro.configs.base import ModelConfig
-        return ModelConfig(
-            name=self.name, family="moe" if self.experts else "dense",
-            num_layers=self.layers, d_model=self.d_model,
-            vocab_size=self.vocab, num_heads=self.heads,
-            num_kv_heads=self.kv_heads, head_dim=self.head_dim,
-            rope_theta=self.rope_theta, d_ff=self.d_ff, mlp_activation="silu",
-            num_experts=self.experts, num_experts_per_tok=self.top_k,
-            norm_eps=self.norm_eps, dtype="bfloat16",
-            tie_embeddings=self.tied)
-
-    @classmethod
-    def from_model_config(cls, cfg, *, slots: int, max_len: int,
-                          cache_dtype: str = "float32",
-                          weights_dtype: str = "float32") -> "Arch":
-        """The sizes of a program ``ModelConfig`` (tests at small sizes)."""
-        return cls(name=cfg.name, layers=cfg.num_layers, d_model=cfg.d_model,
-                   heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
-                   head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=cfg.vocab_size,
-                   experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
-                   tied=cfg.tie_embeddings, rope_theta=cfg.rope_theta,
-                   norm_eps=cfg.norm_eps, slots=slots, max_len=max_len,
-                   cache_dtype=cache_dtype, weights_dtype=weights_dtype)
+        return self.module.model_config(self.sizes)

@@ -3,7 +3,8 @@
   python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file
-(``bench/configs/<config>.json``), a traffic mix
+(``bench/configs/<config>.json``, which names its architecture's model
+module, ``bench/models/<model>.py``), a traffic mix
 (``bench/traffic/<traffic>.json``) and, where the mix needs one, a cell
 file (``bench/cells/<workload>.json``: the fixed arrival rate, the
 correctness limit). Every metric is read by ``bench/metrics/<name>.py``,

@@ -12,10 +12,9 @@ import numpy as np
 import pytest
 
 import check
-import reference
 import run
 import weights
-from arch import Arch
+from bench_small import arch_of
 from repro.configs import get_config
 from repro.serve.engine import Request, ServeEngine
 
@@ -30,7 +29,7 @@ SMALL_GAP_LIMIT = 0.05
 
 def small_arch(name, slots=2, max_len=64):
     cfg = get_config(name).reduced()
-    return cfg, Arch.from_model_config(cfg, slots=slots, max_len=max_len)
+    return cfg, arch_of(cfg, slots=slots, max_len=max_len)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -58,8 +57,8 @@ def test_engine_logits_match_the_reference(name, seed):
     eng.run()
     seq = jnp.asarray(np.concatenate([prompt, np.int32(req.out_tokens[:-1])]))
     rows = slice(len(prompt) - 1, len(prompt) - 1 + len(got))
-    ref = np.asarray(reference.forward(a, params, seq))[rows]
-    ctl = np.asarray(reference.forward(a, params, seq, quant=True))[rows]
+    ref = np.asarray(a.module.forward(a.sizes, params, seq))[rows]
+    ctl = np.asarray(a.module.forward(a.sizes, params, seq, quant=True))[rows]
     assert len(got) == 8
     assert np.abs(np.stack(got) - ref).max() < LOGIT_ATOL
     assert np.abs(ctl - ref).max() > LOGIT_ATOL
@@ -69,7 +68,7 @@ def _cell(seconds_limit=SMALL_GAP_LIMIT):
     spec = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
     cfg = get_config("internlm2-1.8b").reduced(vocab_size=512)
     cell = run.Cell(spec, "internlm2-chat",
-                    arch=Arch.from_model_config(cfg, slots=4, max_len=1024))
+                    arch=arch_of(cfg, slots=4, max_len=1024))
     cell.params = dict(cell.params, logit_gap_limit=seconds_limit)
     return cell
 

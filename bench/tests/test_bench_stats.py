@@ -43,12 +43,17 @@ def test_window_tokens_count_prefills_and_tokens_inside():
     assert stats.window_tokens(recs, 10.0) == 103 + 8
 
 
+def sizes(name):
+    a = Arch.from_file(os.path.join(CONFIGS, f"{name}.json"))
+    return a, a.sizes
+
+
 @pytest.mark.parametrize("name", NAMES)
 def test_config_file_matches_the_program_config(name):
-    a = Arch.from_file(os.path.join(CONFIGS, f"{name}.json"))
+    a, s = sizes(name)
     cfg = get_config(name)
-    assert (a.layers, a.d_model, a.heads, a.kv_heads, a.head_dim, a.d_ff,
-            a.vocab, a.experts, a.top_k, a.tied) == \
+    assert (s.layers, s.d_model, s.heads, s.kv_heads, s.head_dim, s.d_ff,
+            s.vocab, s.experts, s.top_k, s.tied) == \
         (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
          cfg.head_dim, cfg.d_ff, cfg.vocab_size, cfg.num_experts,
          cfg.num_experts_per_tok, cfg.tie_embeddings)
@@ -57,43 +62,43 @@ def test_config_file_matches_the_program_config(name):
 
 @pytest.mark.parametrize("name", NAMES)
 def test_counts_match_the_program_parameter_counts(name):
-    a = Arch.from_file(os.path.join(CONFIGS, f"{name}.json"))
+    a, s = sizes(name)
     cfg = a.model_config()
-    assert flops.param_count(a) == cfg.param_count()
-    assert flops.active_param_count(a) == pytest.approx(
+    assert a.module.param_count(s) == cfg.param_count()
+    assert a.module.active_param_count(s) == pytest.approx(
         cfg.active_param_count(), rel=1e-6)
-    table = a.vocab * a.d_model
-    embed = table * (1 if a.tied else 2)
-    body = flops.active_param_count(a) - embed - (2 * a.layers + 1) * a.d_model
+    table = s.vocab * s.d_model
+    embed = table * (1 if s.tied else 2)
+    body = a.module.active_param_count(s) - embed - (2 * s.layers + 1) * s.d_model
     # a decode token: 2 operations per active body weight, the head, and
     # attention over its cache
     f, _ = flops.decode(a, [100])
-    assert f == 2 * body + 2 * table + 4 * a.layers * a.heads * a.head_dim * 100
+    assert f == 2 * body + 2 * table + 4 * s.layers * s.heads * s.head_dim * 100
     # a prefill of n tokens: n tokens of body, the causal half of the
     # scores, and logits for the last position only
     n = 512
     f, _ = flops.prefill(a, n)
-    assert f == n * 2 * body + 4 * a.layers * a.heads * a.head_dim * n * (n + 1) // 2 \
+    assert f == n * 2 * body + 4 * s.layers * s.heads * s.head_dim * n * (n + 1) // 2 \
         + 2 * table
 
 
 def test_bytes_count_bf16_weights_and_valid_cache_only():
-    a = Arch.from_file(os.path.join(CONFIGS, "internlm2-1.8b.json"))
+    a, s = sizes("internlm2-1.8b")
     _, b1 = flops.decode(a, [10])
     _, b2 = flops.decode(a, [1010])
-    kv_row = 2 * a.layers * a.kv_heads * a.head_dim * 2
+    kv_row = 2 * s.layers * s.kv_heads * s.head_dim * 2
     assert b2 - b1 == 1000 * kv_row
-    weights = flops.param_count(a) - a.vocab * a.d_model    # one table is looked up
-    assert b1 == pytest.approx(2 * weights + 2 * a.d_model + 11 * kv_row)
+    weights = a.module.param_count(s) - s.vocab * s.d_model    # one table is looked up
+    assert b1 == pytest.approx(2 * weights + 2 * s.d_model + 11 * kv_row)
 
 
 def test_moe_reads_only_the_experts_a_call_can_touch():
-    a = Arch.from_file(os.path.join(CONFIGS, "granite-moe-1b-a400m.json"))
+    a, s = sizes("granite-moe-1b-a400m")
     _, one = flops.decode(a, [10])
     _, many = flops.decode(a, [10] * 64)
-    expert = 3 * a.d_model * a.d_ff * 2 * a.layers
+    expert = 3 * s.d_model * s.d_ff * 2 * s.layers
     # one token reads its top-8 experts; 64 tokens nearly all 32
     assert many - one == pytest.approx(
         (32 * (1 - (1 - 8 / 32) ** 64) - 8) * expert
-        + 63 * (2 * a.d_model + 2 * 2 * a.layers * a.kv_heads * a.head_dim * 11),
+        + 63 * (2 * s.d_model + 2 * 2 * s.layers * s.kv_heads * s.head_dim * 11),
         rel=1e-9)
